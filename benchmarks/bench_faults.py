@@ -231,6 +231,8 @@ def sharded_chaos_rows(budget: str = "small", n_devices: int = 4):
     rows.append(fault_row(rep_o, n, "inflight_overlap", "nan_transient",
                           devices=n_devices))
     parity_ok = parity_ok and records_bitwise_equal(rep_s, rep_o)
+    for r in rows:
+        r["backend"] = jax.default_backend()
     return rows, parity_ok
 
 
@@ -246,6 +248,9 @@ def _start_sharded_section(budget: str):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO_ROOT, "src")
     env.pop("XLA_FLAGS", None)
+    # the forced devices are CPU devices; a child that asked for the
+    # accelerator would contend with this process, which holds it
+    env["JAX_PLATFORMS"] = "cpu"
     return subprocess.Popen([sys.executable, "-c", script], env=env,
                             stdout=subprocess.PIPE,
                             stderr=subprocess.PIPE, text=True,

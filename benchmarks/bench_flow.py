@@ -96,14 +96,14 @@ def _reference(model, xs: np.ndarray) -> np.ndarray:
     ref = Integrator(tableau=model.integ.tableau)
 
     @jax.jit
-    def run(x):
-        z0 = model.embed(x)
+    def run(p, x):
+        z0 = model.embed(p, x)
         Ks = jnp.full((x.shape[0],), REF_K, jnp.int32)
-        zT = ref.solve_multirate(model.field_of(x), z0, model.span, Ks,
+        zT = ref.solve_multirate(model.field_of(p, x), z0, model.span, Ks,
                                  REF_K)
-        return model.readout(x, zT)
+        return model.readout(p, x, zT)
 
-    return np.asarray(run(jnp.asarray(xs)))
+    return np.asarray(run(model.params, jnp.asarray(xs)))
 
 
 def _trained_model(budget: str):
@@ -123,8 +123,8 @@ def _trained_model(budget: str):
 
     xs = heterogeneous_requests(b["n_train"], D_FEAT, seed=1)
     h = model.span[1] - model.span[0]
-    z0 = model.embed(jnp.asarray(xs))
-    dz0 = model.field_of(jnp.asarray(xs))(model.span[0], z0)
+    z0 = model.embed(model.params, jnp.asarray(xs))
+    dz0 = model.field_of(model.params, jnp.asarray(xs))(model.span[0], z0)
     z_mid = z0 + (h / 2) * dz0          # a half-span Euler interior state
     n = len(xs)
     for K in (1,) + BUCKETS:            # K=1 is the flow tier's eps = h

@@ -74,10 +74,10 @@ N_CLASSES = 10
 def reference_argmax(model: DepthModel, xs: np.ndarray) -> np.ndarray:
     """Fine-mesh ground truth (K=64 base-tableau solve, no correction)."""
     integ = Integrator(tableau=model.integ.tableau)
-    x = jnp.asarray(xs)
-    zT = integ.solve(model.field_of(x), model.embed(x),
+    x, p = jnp.asarray(xs), model.params
+    zT = integ.solve(model.field_of(p, x), model.embed(p, x),
                      FixedGrid.over(0.0, 1.0, 64), return_traj=False)
-    return np.asarray(jnp.argmax(model.readout(x, zT), -1))
+    return np.asarray(jnp.argmax(model.readout(p, x, zT), -1))
 
 
 def _agreement(records, ref_top) -> float:
@@ -154,7 +154,7 @@ def sharded_rows(budget: str = "small", n_devices: int = 4):
             row.update(bench="scheduler", mode="inflight",
                        trace=f"poisson_hot_seed{seed}", solver="euler",
                        slots=slots_per_dev * devices, seg=seg,
-                       devices=devices,
+                       devices=devices, backend=jax.default_backend(),
                        agreement=round(_agreement(rep.records, ref_top),
                                        4))
             pair.append(row)
@@ -238,6 +238,9 @@ def _start_sharded_section(budget: str):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO_ROOT, "src")
     env.pop("XLA_FLAGS", None)
+    # the forced devices are CPU devices; a child that asked for the
+    # accelerator would contend with this process, which holds it
+    env["JAX_PLATFORMS"] = "cpu"
     return subprocess.Popen([sys.executable, "-c", script], env=env,
                             stdout=subprocess.PIPE,
                             stderr=subprocess.PIPE, text=True,

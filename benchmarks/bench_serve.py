@@ -30,6 +30,7 @@ import argparse
 import json
 import os
 import sys
+from functools import partial
 
 if __name__ == "__main__":  # runnable as a script from anywhere
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
@@ -230,7 +231,7 @@ def lm_workload(budget: str, solver: str):
     toks = np.concatenate([easy, hard], axis=0).astype(np.int32)
 
     h0 = _embed(params, cfg, jnp.asarray(toks))
-    f = depth_field(params, cfg)
+    f = partial(depth_field(cfg), params)
     ref_traj, ref_nfe = odeint_dopri5(f, h0, FixedGrid.over(0.0, 1.0, 1),
                                       atol=1e-3, rtol=1e-3)
     ref_logits = apply_tail(params, cfg, ref_traj[-1])

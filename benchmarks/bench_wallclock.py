@@ -158,10 +158,10 @@ def _mechanism_row(d: int = 4096, slots: int = 8, seg: int = 8):
     for the jit call to RETURN (dispatch) vs wall time for the retire
     meta to materialize (execute). The gap is the window the overlap
     loop fills with host-side admit/probe/retire work. Measured in both
-    donate modes: on the CPU client (jaxlib 0.4.x) a DONATING call runs
-    synchronously — dispatch collapses onto execute — which is why the
-    scheduler's ``donate`` auto-default keeps donation off on CPU
-    (``InflightScheduler.__init__``)."""
+    donate modes: ``donated_dispatch_us`` shows whether a DONATING call
+    still returns before the segment finishes on this backend (the
+    scheduler's ``donate`` auto-default keeps donation off on CPU —
+    ``InflightScheduler.__init__``)."""
     from repro.launch.workload import heterogeneous_requests, toy_classifier
 
     m = toy_classifier("euler", d=d)
@@ -172,14 +172,15 @@ def _mechanism_row(d: int = 4096, slots: int = 8, seg: int = 8):
     times = {}
     for donate in (False, True):
         cell = m.integ.segment_cell(m.field_of, seg, donate=donate)
+        params = m.params
         z = jnp.zeros((slots, d), jnp.float32)
         fs = jnp.zeros((slots, d), jnp.float32)
-        z, fs, meta = cell(xs, z, k, Ks, eps, fs)   # compile
+        z, fs, meta = cell(params, xs, z, k, Ks, eps, fs)   # compile
         np.array(meta)
         dispatch, execute = [], []
         for _ in range(11):
             t0 = time.perf_counter()
-            z, fs, meta = cell(xs, z, k, Ks, eps, fs)
+            z, fs, meta = cell(params, xs, z, k, Ks, eps, fs)
             t1 = time.perf_counter()
             np.array(meta)              # block until the segment finishes
             t2 = time.perf_counter()

@@ -401,6 +401,8 @@ def main() -> None:
         print(f"# BENCH-CHECK OK: {sorted(BENCH_REQUIRED)}")
         return
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     out_dir = os.path.join(os.path.dirname(__file__), "..", "artifacts")
     os.makedirs(out_dir, exist_ok=True)
     all_rows = []

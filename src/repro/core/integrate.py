@@ -600,18 +600,18 @@ class Integrator:
         return SegmentCarry(z, k, Ks, eps, fs), k >= Ks
 
     def _solve_segment_sharded(self, f, carry, seg, s0, mesh, slot_axis,
-                               *, field_of=None, cond=None):
+                               *, field_of=None, cond=None, params=None):
         """Slot-parallel segment advance: shard every carry field over
         ``slot_axis`` and run the local ``solve_segment`` per shard. Only
         the fields the segment mutates (z, k) cross back through the
         shard_map boundary — Ks/eps/first_stage pass through unchanged.
 
         With ``field_of``/``cond`` (launch/mesh.py::sharded_segment),
-        the per-slot conditioning rows ``cond`` shard WITH the carry and
-        each shard's field is rebuilt as ``field_of(cond_local)``; ``f``
-        is ignored. Both entry points share this one plumbing so the
-        divisibility policy and the spec layout cannot diverge."""
-        from jax.experimental.shard_map import shard_map
+        the per-slot conditioning rows ``cond`` shard WITH the carry, the
+        model weights ``params`` enter replicated (``P()``), and each
+        shard's field is rebuilt as ``field_of(params, cond_local)``;
+        ``f`` is ignored. Both entry points share this one plumbing so
+        the divisibility policy and the spec layout cannot diverge."""
         P = jax.sharding.PartitionSpec
         tmap = jax.tree_util.tree_map
         bspec = P(slot_axis)
@@ -624,8 +624,8 @@ class Integrator:
                 "(launch/scheduler.py slots=)")
         z, k, Ks, eps, fs = carry
         threaded = cond is not None
-        args = ([cond] if threaded else []) + [z, k, Ks, eps]
-        in_specs = ([bspec] if threaded else []) + \
+        args = ([params, cond] if threaded else []) + [z, k, Ks, eps]
+        in_specs = ([P(), bspec] if threaded else []) + \
             [tmap(lambda _: bspec, z), bspec, bspec, bspec]
         if fs is not None:
             args.append(fs)
@@ -633,24 +633,25 @@ class Integrator:
 
         def body(*ops):
             if threaded:
-                cond_, *ops = ops
+                params_, cond_, *ops = ops
             z_, k_, Ks_, eps_, *fs_ = ops
             local = SegmentCarry(z_, k_, Ks_, eps_,
                                  fs_[0] if fs_ else None)
-            f_local = field_of(cond_) if threaded else f
+            f_local = field_of(params_, cond_) if threaded else f
             out, fin = self.solve_segment(f_local, local, seg, s0=s0)
             return out.z, out.k, fin
 
         out_specs = (tmap(lambda _: bspec, z), bspec, bspec)
-        z2, k2, fin = shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                                out_specs=out_specs, check_rep=False)(*args)
+        z2, k2, fin = jax.shard_map(
+            body, mesh=mesh, in_specs=tuple(in_specs), out_specs=out_specs,
+            check_vma=False)(*args)
         return SegmentCarry(z2, k2, Ks, eps, fs), fin
 
     def segment_cell(self, field_of, seg: int, *, s0=0.0, mesh=None,
                      slot_axis: str = "data", donate: bool = True,
                      g_apply=None):
         """The serving-loop compilation of ``solve_segment``: one jitted
-        ``(xs, z, k, Ks, eps, fs) -> (z', fs', meta)`` cell per
+        ``(params, xs, z, k, Ks, eps, fs) -> (z', fs', meta)`` cell per
         ``(shape, seg[, mesh])``, with the carry buffers DONATED.
 
         The donation contract (``donate_argnums``): the big per-slot
@@ -682,10 +683,13 @@ class Integrator:
         None``) the slot contributes no donated buffer and the cell
         degrades gracefully.
 
-        ``field_of`` builds the slot-local vector field from the per-slot
-        conditioning rows ``xs`` (the launch/engine.py ``DepthModel``
-        adapter shape); under ``mesh=`` the rows thread through the same
-        shard_map as the carry (``_solve_segment_sharded``).
+        ``field_of(params, xs)`` builds the slot-local vector field from
+        the model weights and the per-slot conditioning rows ``xs`` (the
+        launch/engine.py ``DepthModel`` adapter shape). ``params`` is a
+        traced, non-donated operand — the weights are never constants of
+        the compiled cell. Under ``mesh=`` the rows thread through the
+        same shard_map as the carry and the weights enter it replicated
+        (``_solve_segment_sharded``).
 
         ``g_apply`` turns the correction into a HOT-SWAPPABLE operand:
         instead of baking g's parameters into the closure (a constant of
@@ -699,16 +703,16 @@ class Integrator:
         on this path. The non-parametric signature and its donation
         contract are unchanged."""
 
-        def _advance(integ, xs, z, k, Ks, eps, fs):
+        def _advance(integ, params, xs, z, k, Ks, eps, fs):
             carry = SegmentCarry(z, jnp.asarray(k, jnp.int32),
                                  jnp.asarray(Ks, jnp.int32), eps, fs)
             if mesh is None:
-                out, fin = integ.solve_segment(field_of(xs), carry, seg,
-                                               s0=s0)
+                out, fin = integ.solve_segment(field_of(params, xs), carry,
+                                               seg, s0=s0)
             else:
                 out, fin = integ._solve_segment_sharded(
                     None, carry, seg, s0, mesh, slot_axis,
-                    field_of=field_of, cond=xs)
+                    field_of=field_of, cond=xs, params=params)
             bad = _nonfinite_rows(out.z, like=fin)
             meta = jnp.stack([out.k.astype(jnp.int32),
                               fin.astype(jnp.int32),
@@ -716,16 +720,16 @@ class Integrator:
             return out.z, out.first_stage, meta
 
         if g_apply is None:
-            def run(xs, z, k, Ks, eps, fs):
-                return _advance(self, xs, z, k, Ks, eps, fs)
+            def run(params, xs, z, k, Ks, eps, fs):
+                return _advance(self, params, xs, z, k, Ks, eps, fs)
         else:
-            def run(xs, z, k, Ks, eps, fs, gp):
+            def run(params, xs, z, k, Ks, eps, fs, gp):
                 bound = dataclasses.replace(
                     self,
                     g=lambda e, s, zz, dzz: g_apply(gp, e, s, zz, dzz))
-                return _advance(bound, xs, z, k, Ks, eps, fs)
+                return _advance(bound, params, xs, z, k, Ks, eps, fs)
 
-        return jax.jit(run, donate_argnums=(1, 5) if donate else ())
+        return jax.jit(run, donate_argnums=(2, 6) if donate else ())
 
     def _solve_controlled(self, f, z0, grid, controller, return_traj,
                           checkpoint):
@@ -758,7 +762,6 @@ class Integrator:
         nothing — the runtime-eps kernel looks its per-row coefficients up
         from prefetched SMEM — so the body emits no collectives and the
         wrapper is pure bookkeeping (specs in, specs out)."""
-        from jax.experimental.shard_map import shard_map
         P = jax.sharding.PartitionSpec
         tmap = jax.tree_util.tree_map
         bspec = P(batch_axis)
@@ -786,8 +789,8 @@ class Integrator:
         res_spec = P(None, batch_axis) if return_traj else bspec
         out_specs = (res_spec, (bspec, bspec, bspec)) \
             if controller is not None else res_spec
-        out = shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                        out_specs=out_specs, check_rep=False)(*args)
+        out = jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                            out_specs=out_specs, check_vma=False)(*args)
         if controller is None:
             return out
         res, (nfe, K, err) = out

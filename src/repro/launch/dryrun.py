@@ -25,7 +25,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import ARCH_IDS, SHAPES, cell_is_applicable, get
 from repro.distributed import sharding as shd
-from repro.launch.mesh import batch_axes, make_production_mesh, mesh_context
+from repro.launch.mesh import batch_axes, make_production_mesh
 from repro.launch.steps import (
     StepSettings, data_shardings, input_specs, make_prefill_step,
     make_serve_step, make_train_step,
@@ -112,7 +112,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     settings = settings or TRAIN_SETTINGS.get(arch, TRAIN_SETTINGS["_default"])
     mesh = mesh if mesh is not None else make_production_mesh(multi_pod=multi_pod)
     t0 = time.time()
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         if settings.seq_shard and shape.kind != "decode":
             shd.set_activation_sharding(batch_axes(mesh), seq_axis="model")
         else:

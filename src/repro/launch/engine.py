@@ -42,6 +42,7 @@ from __future__ import annotations
 
 import dataclasses
 from collections import deque
+from functools import partial
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -112,10 +113,15 @@ def load_flow_params(path: str, cfg: ArchConfig, rank: int = 64):
 class DepthModel:
     """What the engine needs to serve a continuous-depth model.
 
-    ``embed(x)`` lifts a request batch to the ODE state z0; ``field_of(x)``
-    closes the vector field over any conditioning; ``readout(x, zT)`` maps
-    the terminal state to outputs (logits). ``integ`` is the serving
-    Integrator (base tableau + optional correction g).
+    ``embed(params, x)`` lifts a request batch to the ODE state z0;
+    ``field_of(params, x)`` builds the vector field over any
+    conditioning; ``readout(params, x, zT)`` maps the terminal state to
+    outputs (logits). ``params`` is the model's weight pytree: every
+    serving jit cell takes it as a traced, non-donated operand, so no
+    cell compiles the weights in as constants (at published LM widths
+    those constants would exceed what one serialized program may hold).
+    ``integ`` is the serving Integrator (base tableau + optional
+    correction g).
 
     Two ways to carry a hypersolver correction:
 
@@ -139,10 +145,11 @@ class DepthModel:
     params-are-inputs contract as g (traced cell operands,
     ``hot_swap_flow``)."""
 
-    embed: Callable[[Any], Any]
-    field_of: Callable[[Any], Callable]
-    readout: Callable[[Any, Any], Any]
+    embed: Callable[[Any, Any], Any]
+    field_of: Callable[[Any, Any], Callable]
+    readout: Callable[[Any, Any, Any], Any]
     integ: Integrator
+    params: Any = None                   # model weights, a cell operand
     span: Tuple[float, float] = (0.0, 1.0)
     g_apply: Optional[Callable] = None   # g_apply(gp, eps, s, z, dz)
     g_params: Any = None                 # initial swappable params
@@ -212,7 +219,7 @@ def lm_depth_model(params, cfg: ArchConfig, solver: str = "euler",
     )
     from repro.models.lm import _embed
 
-    f = depth_field(params, cfg)
+    field = depth_field(cfg)
     kw = {}
     if refinable:
         base = solver[len("hyper_"):] if solver.startswith("hyper_") \
@@ -234,10 +241,11 @@ def lm_depth_model(params, cfg: ArchConfig, solver: str = "euler",
                 lm_flow_apply(fp, eps, s, z, dz, order=order),
             flow_params=flow_params)
     return DepthModel(
-        embed=lambda toks: _embed(params, cfg, toks),
-        field_of=lambda toks: f,
-        readout=lambda toks, h: apply_tail(params, cfg, h),
+        embed=lambda p, toks: _embed(p, cfg, toks),
+        field_of=lambda p, toks: partial(field, p),
+        readout=lambda p, toks, h: apply_tail(p, cfg, h),
         integ=integ,
+        params=params,
         **kw,
     )
 
@@ -261,10 +269,11 @@ def node_depth_model(node, params, solver: str = "euler",
     else:
         base = solver
     return DepthModel(
-        embed=lambda x: node.hx_apply(params, x),
-        field_of=lambda x: node.field(params, x),
-        readout=lambda x, zT: node.hy_apply(params, zT),
+        embed=node.hx_apply,
+        field_of=node.field,
+        readout=lambda p, x, zT: node.hy_apply(p, zT),
         integ=make_integrator(base, g_apply, g_params, None, fused=fused),
+        params=params,
         span=tuple(node.s_span),
     )
 
@@ -533,6 +542,9 @@ class MultiRateEngine:
                              "(a zero-width queue can never admit)")
         self.model = prepare_model(model, engine_cfg)
         self.ecfg = engine_cfg
+        # model weights: one device-resident copy, passed into every jit
+        # cell as a traced operand (never a compiled-in constant)
+        self.params = jax.tree_util.tree_map(jnp.asarray, self.model.params)
         # controller policy decides off the BOUND integrator (a parametric
         # g counts as a correction for controller="auto"); the cells
         # re-bind g over the traced gp operand themselves
@@ -599,7 +611,7 @@ class MultiRateEngine:
         sample K before bucket snapping, per-sample error estimate)."""
         xs = np.asarray(xs)
         Ks, errs, _, _ = self._probe_fn(xs.shape[1:])(
-            jnp.asarray(xs), *self._g_args())
+            self.params, jnp.asarray(xs), *self._g_args())
         return np.asarray(Ks), np.asarray(errs)
 
     # ----------------------------------------------------------- queue ----
@@ -656,13 +668,13 @@ class MultiRateEngine:
             parametric = m.g_apply is not None
 
             @jax.jit
-            def probe(x, *gps):
+            def probe(params, x, *gps):
                 # parametric g rides as a traced operand (gps = (gp,)),
                 # so swapped params reuse this compilation
                 integ = bound_integrator(m, gps[0]) if parametric \
                     else m.integ
-                z0 = m.embed(x)
-                p = ctrl.select(integ, m.field_of(x), z0, m.span)
+                z0 = m.embed(params, x)
+                p = ctrl.select(integ, m.field_of(params, x), z0, m.span)
                 return p.K, p.err, z0, p.dz0
 
             self._probe_fns[shape] = probe
@@ -693,8 +705,9 @@ class MultiRateEngine:
             s0 = m.span[0]
 
             @jax.jit
-            def flow(x, z0, dz0, *fps):
-                return m.readout(x, m.flow_apply(fps[0], h, s0, z0, dz0))
+            def flow(params, x, z0, dz0, *fps):
+                return m.readout(params, x,
+                                 m.flow_apply(fps[0], h, s0, z0, dz0))
 
             self._flow_fns[shape] = flow
         return self._flow_fns[shape]
@@ -706,7 +719,7 @@ class MultiRateEngine:
             parametric = m.g_apply is not None
 
             @jax.jit
-            def solve(x, z0, dz0, Ks, *gps):
+            def solve(params, x, z0, dz0, Ks, *gps):
                 # z0/dz0 come from the probe cell (embed + first stage are
                 # not recomputed); the fixed path passes z0=None and
                 # embeds here. Ks is a TRACED (B,) row: sample i runs its
@@ -718,10 +731,11 @@ class MultiRateEngine:
                 integ = bound_integrator(m, gps[0]) if parametric \
                     else m.integ
                 if z0 is None:
-                    z0 = m.embed(x)
+                    z0 = m.embed(params, x)
                 zT = integ.solve_multirate(
-                    m.field_of(x), z0, m.span, Ks, k_max, first_stage=dz0)
-                return m.readout(x, zT)
+                    m.field_of(params, x), z0, m.span, Ks, k_max,
+                    first_stage=dz0)
+                return m.readout(params, x, zT)
 
             self._solve_fns[key] = solve
         return self._solve_fns[key]
@@ -816,7 +830,7 @@ class MultiRateEngine:
                 z0 = dz0 = None
             else:
                 Ks_dev, err_dev, z0, dz0 = self._probe_fn(shape)(
-                    jnp.asarray(xs), *self._g_args())
+                    self.params, jnp.asarray(xs), *self._g_args())
                 Ks_raw = np.asarray(Ks_dev)
                 errs = np.asarray(err_dev)
                 probe_nonfinite += screen_probe_errors(errs)
@@ -850,7 +864,7 @@ class MultiRateEngine:
                 # bitwise identical to capture-disabled ones.
                 span = self.model.span
                 z_cap = z0 if z0 is not None else \
-                    self._embed_fn(shape)(jnp.asarray(xs))
+                    self._embed_fn(shape)(self.params, jnp.asarray(xs))
                 self.ledger.capture(
                     jnp.asarray(xs), z_cap,
                     np.full(len(reqs), span[0], np.float32),
@@ -868,7 +882,7 @@ class MultiRateEngine:
             # honest; the fixed path has no probe z0, so eval_shape the
             # embedding (dtypes only, no compute)
             z_like = z0 if z0 is not None else jax.eval_shape(
-                self.model.embed,
+                self.model.embed, self.params,
                 jax.ShapeDtypeStruct(xs.shape, xs.dtype))
             fused = self.fused_in_play(z_like)
 
@@ -887,7 +901,7 @@ class MultiRateEngine:
             fidx = np.flatnonzero(flow_sel)
             if len(fidx):
                 f_out = np.asarray(self._flow_fn(shape)(
-                    jnp.asarray(xs[fidx]), take(z0, fidx),
+                    self.params, jnp.asarray(xs[fidx]), take(z0, fidx),
                     take(dz0, fidx), *self._flow_args()))
                 cost += self.oracle.flow_cost(shape, len(fidx))
                 for j, i in enumerate(fidx):
@@ -938,7 +952,7 @@ class MultiRateEngine:
                 k_max = int(Ks[sel].max())
                 outputs = np.asarray(
                     self._solve_fn(shape, k_max)(
-                        jnp.asarray(xs[sel]), take(z0, sel),
+                        self.params, jnp.asarray(xs[sel]), take(z0, sel),
                         take(dz0, sel), jnp.asarray(Ks[sel], jnp.int32),
                         *self._g_args()))
                 cost += self.oracle.solve_cost(shape, k_max, len(sel),

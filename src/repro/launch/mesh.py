@@ -3,10 +3,22 @@
 Defined as FUNCTIONS (not module-level constants) so importing this module
 never touches jax device state — the dry-run must set
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before first init.
+
+Every mesh here has ``Auto`` axes: the sharding rules
+(distributed/sharding.py) and the serving pools leave placement inside a
+program to the compiler. ``jax.make_mesh`` defaults to ``Explicit`` axes,
+under which an un-annotated gather (the embedding lookup, a slot-pool
+scatter) is a ``ShardingTypeError``.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -15,13 +27,13 @@ def make_production_mesh(*, multi_pod: bool = False):
     outer data-parallel axis crossing DCN (only gradient all-reduce)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_debug_mesh(n_data: int = 2, n_model: int = 4):
     """Small mesh for CPU sharding-semantics tests (run in a subprocess with
     a forced host device count — never in the main test process)."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return auto_mesh((n_data, n_model), ("data", "model"))
 
 
 def make_serving_mesh(n_devices: int):
@@ -36,19 +48,12 @@ def make_serving_mesh(n_devices: int):
             f"--mesh {n_devices} asks for more devices than visible "
             f"({jax.device_count()}); on CPU force virtual devices with "
             "XLA_FLAGS=--xla_force_host_platform_device_count=N")
-    return jax.make_mesh((n_devices,), ("data",))
+    return auto_mesh((n_devices,), ("data",))
 
 
 def batch_axes(mesh) -> tuple:
     """Mesh axes the global batch dimension shards over."""
     return ("pod", "data") if "pod" in mesh.axis_names else ("data",)
-
-
-def mesh_context(mesh):
-    """Ambient-mesh context manager across jax versions: ``jax.set_mesh``
-    where it exists (jax >= 0.5), else the Mesh's own context manager."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
 
 
 def sharded_solve(integ, f, z0, grid, *, mesh, **solve_kwargs):
@@ -78,21 +83,22 @@ def sharded_solve(integ, f, z0, grid, *, mesh, **solve_kwargs):
                        **solve_kwargs)
 
 
-def sharded_segment(integ, field_of, xs, carry, seg, *, mesh, s0=0.0,
-                    slot_axis: str = "data"):
+def sharded_segment(integ, field_of, params, xs, carry, seg, *, mesh,
+                    s0=0.0, slot_axis: str = "data"):
     """Slot-axis-sharded segment advance WITH per-slot conditioning: the
     multi-device twin of ``Integrator.solve_segment(mesh=)`` for fields
-    that condition on the request input (``field_of(x)`` closures —
+    that condition on the request input (``field_of(params, x)`` —
     launch/engine.py DepthModel adapters).
 
     ``Integrator.solve_segment(mesh=)`` shards the SegmentCarry rows but
-    treats whatever ``f`` closes over as replicated — correct for model
-    params, wrong for per-slot conditioning (a field closed over the FULL
-    ``xs`` rows would see B conditioning rows against B/n state rows
-    inside a shard). This helper threads ``xs`` through the same
-    ``shard_map``, so ``field_of`` is rebuilt per shard from exactly its
-    slots' conditioning rows. Returns ``(carry', finished)`` like
-    ``solve_segment``; everything stays slot-major and collective-free.
+    treats whatever ``f`` closes over as replicated — wrong for per-slot
+    conditioning (a field closed over the FULL ``xs`` rows would see B
+    conditioning rows against B/n state rows inside a shard). This helper
+    threads ``xs`` through the same ``shard_map``, and the weights
+    ``params`` with it, replicated, so ``field_of`` is rebuilt per shard
+    from the weights and exactly its slots' conditioning rows. Returns
+    ``(carry', finished)`` like ``solve_segment``; everything stays
+    slot-major and collective-free.
 
     Thin wrapper over the one shard_map plumbing in
     ``Integrator._solve_segment_sharded`` (shared with
@@ -101,14 +107,14 @@ def sharded_segment(integ, field_of, xs, carry, seg, *, mesh, s0=0.0,
     cannot diverge between the two entry points)."""
     return integ._solve_segment_sharded(
         None, carry, seg, s0, mesh, slot_axis, field_of=field_of,
-        cond=xs)
+        cond=xs, params=params)
 
 
 def sharded_segment_cell(integ, field_of, seg, *, mesh, s0=0.0,
                          slot_axis: str = "data", donate: bool = True,
                          g_apply=None):
     """The donated jit compilation of ``sharded_segment``: one
-    ``(xs, z, k, Ks, eps, fs) -> (z', fs', meta)`` cell per
+    ``(params, xs, z, k, Ks, eps, fs) -> (z', fs', meta)`` cell per
     ``(shape, seg, mesh)`` with the pool-sized carry buffers (z, fs)
     donated, exactly like the single-device ``Integrator.segment_cell``
     — sharding changes which device owns which slot rows, never the

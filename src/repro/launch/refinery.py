@@ -116,7 +116,7 @@ class ResidualLedger:
 
     # ------------------------------------------------------ capture cells ----
     def _cell(self, shape: Tuple[int, ...], dtype, width: int):
-        """One jitted ``(xs, z, s, eps) -> (dz, R)`` capture cell per
+        """One jitted ``(params, xs, z, s, eps) -> (dz, R)`` capture cell per
         (request shape, row width): base-tableau stage evals + psi, a
         finer RK4 reference (two half steps), and the Eq. 6 residual —
         all row-wise, nothing mutated."""
@@ -128,8 +128,8 @@ class ResidualLedger:
             p1 = tab.order + 1
 
             @jax.jit
-            def cap(xs, z, s, eps):
-                f = m.field_of(xs)
+            def cap(params, xs, z, s, eps):
+                f = m.field_of(params, xs)
                 stages = rk_stages(f, tab, s, eps, z)
                 dz = stages[0]
                 psi = tree_lincomb(tab.b, stages)
@@ -200,7 +200,7 @@ class ResidualLedger:
         mask = np.ones(B, bool) if keep is None else \
             np.asarray(keep, bool).copy()
         cell = self._cell(tuple(xs.shape[1:]), xs.dtype, w)
-        dz, R = cell(xs, z, jnp.asarray(s, jnp.float32),
+        dz, R = cell(self.model.params, xs, z, jnp.asarray(s, jnp.float32),
                      jnp.asarray(eps, jnp.float32))
         return self._ingest(np.asarray(s), np.asarray(eps), z, dz, R,
                             np.flatnonzero(mask))
@@ -219,8 +219,8 @@ class ResidualLedger:
              * pool.eps.astype(np.float64)).astype(np.float32)
         cell = self._cell(tuple(pool.shape), pool.xs.dtype,
                           int(pool.k.shape[0]))
-        dz, R = cell(pool._xs_dev, pool.z, jnp.asarray(s),
-                     jnp.asarray(pool.eps, jnp.float32))
+        dz, R = cell(pool.sched.params, pool._xs_dev, pool.z,
+                     jnp.asarray(s), jnp.asarray(pool.eps, jnp.float32))
         return self._ingest(s, pool.eps, pool.z, dz, R, rows)
 
     def _ingest(self, s, eps, z, dz, R, rows) -> int:
@@ -422,8 +422,8 @@ class Refinery:
                     model, ecfg or EngineConfig())
             else:
                 self._flow_score_fn = self._flow_cell()
-            self._ref_out = np.asarray(
-                self._reference_cell()(jnp.asarray(self._shadow_xs)))
+            self._ref_out = np.asarray(self._reference_cell()(
+                model.params, jnp.asarray(self._shadow_xs)))
 
         self._ckpt = None
         if ckpt_dir is not None:
@@ -463,11 +463,12 @@ class Refinery:
         ref = Integrator(tableau=m.integ.tableau)
 
         @jax.jit
-        def run(xs):
-            z0 = m.embed(xs)
+        def run(params, xs):
+            z0 = m.embed(params, xs)
             Ks = jnp.full((xs.shape[0],), K, jnp.int32)
-            zT = ref.solve_multirate(m.field_of(xs), z0, m.span, Ks, K)
-            return m.readout(xs, zT)
+            zT = ref.solve_multirate(m.field_of(params, xs), z0, m.span,
+                                     Ks, K)
+            return m.readout(params, xs, zT)
 
         return run
 
@@ -482,10 +483,10 @@ class Refinery:
         fa = m.flow_apply
 
         @jax.jit
-        def run(xs, fp):
-            z0 = m.embed(xs)
-            dz0 = m.field_of(xs)(s0, z0)
-            return m.readout(xs, fa(fp, h, s0, z0, dz0))
+        def run(params, xs, fp):
+            z0 = m.embed(params, xs)
+            dz0 = m.field_of(params, xs)(s0, z0)
+            return m.readout(params, xs, fa(fp, h, s0, z0, dz0))
 
         return run
 
@@ -510,7 +511,7 @@ class Refinery:
             out["mean_nfe"] = float(np.mean([c.nfe for c in recs]))
         elif self._flow_score_fn is not None:
             outs = np.asarray(self._flow_score_fn(
-                jnp.asarray(self._shadow_xs), gp))
+                self.model.params, jnp.asarray(self._shadow_xs), gp))
             out["agreement"] = self._score_fn(outs, self._ref_out)
         hb = self.ledger.holdout_batch(self.cfg.holdout_rows)
         if hb is not None:

@@ -283,19 +283,17 @@ class _SlotPool:
             parametric = m.g_apply is not None
 
             @jax.jit
-            def probe(xs, *gps):
+            def probe(params, xs, *gps):
                 # on a parametric model the correction params ride as a
                 # traced operand (gps = (gp,)) — the residual controller
                 # consumes g in the probe, so the probe cell must be
                 # swap-stable too (no retrace on hot_swap_g)
                 ig = bound_integrator(m, gps[0]) if parametric else integ
-                z0 = m.embed(xs)
-                p = ctrl.select(ig, m.field_of(xs), z0, m.span)
+                z0 = m.embed(params, xs)
+                p = ctrl.select(ig, m.field_of(params, xs), z0, m.span)
                 return p.K, p.err, z0, p.dz0
 
-            @jax.jit
-            def embed(xs):
-                return m.embed(xs)
+            embed = jax.jit(m.embed)
 
             # the segment cell donates the pool-sized carry buffers
             # (z, fs) — Integrator.segment_cell documents the aliasing
@@ -304,9 +302,11 @@ class _SlotPool:
             # shard over the mesh's slot axis and the depth scan stays
             # local per shard; either way this is ONE
             # (shape, seg[, mesh]) jit cell — one fused-kernel trace —
-            # across every refill pattern. A parametric g appends its
-            # params as a trailing traced (non-donated) operand: the
-            # params-are-inputs invariant that makes hot_swap_g free.
+            # across every refill pattern. The model weights lead as a
+            # traced (non-donated) operand, and a parametric g appends
+            # its params as a trailing one: the params-are-inputs
+            # invariant that keeps weights out of the compiled program
+            # and makes hot_swap_g free.
             mesh = self.sched.mesh
             donate = self.sched.donate
             g_apply = m.g_apply
@@ -321,22 +321,20 @@ class _SlotPool:
                     slot_axis=self.sched.slot_axis, donate=donate,
                     g_apply=g_apply)
 
-            @jax.jit
-            def readout(xs, z):
-                return m.readout(xs, z)
+            readout = jax.jit(m.readout)
 
             if m.flow_apply is not None:
                 h, fs0 = m.span[1] - m.span[0], m.span[0]
 
                 @jax.jit
-                def flow(xs, z0, dz0, *fps):
+                def flow(params, xs, z0, dz0, *fps):
                     # the K=0 tier: one flow-head eval + readout over the
                     # admission probe's already-materialized (z0, dz0);
                     # flow params ride as a traced trailing operand (the
                     # params-are-inputs invariant, same as g). Widths are
                     # pow2-gated by the caller like _readout_finished.
-                    return m.readout(xs, m.flow_apply(fps[0], h, fs0,
-                                                      z0, dz0))
+                    return m.readout(params, xs, m.flow_apply(
+                        fps[0], h, fs0, z0, dz0))
 
                 self._flow_fn = flow
 
@@ -385,14 +383,14 @@ class _SlotPool:
         fixed = isinstance(sched.controller, FixedController)
         probe_nonfinite = 0
         if fixed:
-            z0 = embed_fn(jnp.asarray(xs_pad))
+            z0 = embed_fn(sched.params, jnp.asarray(xs_pad))
             dz0 = None
             Ks_raw = np.full((len(reqs),), sched.controller.K, np.int32)
             errs = np.zeros((len(reqs),), np.float32)
             probe_cost = 0.0
         else:
-            Ks_dev, err_dev, z0, dz0 = probe_fn(jnp.asarray(xs_pad),
-                                                *sched._g_args())
+            Ks_dev, err_dev, z0, dz0 = probe_fn(
+                sched.params, jnp.asarray(xs_pad), *sched._g_args())
             Ks_raw = np.asarray(Ks_dev)[:len(reqs)]
             errs = np.asarray(err_dev)[:len(reqs)]
             # the silent k_max clamp in mesh_for_tolerance becomes an
@@ -506,8 +504,8 @@ class _SlotPool:
             [fidx, np.repeat(fidx[:1], w - len(fidx))])
         jf = jnp.asarray(pad)
         gather = lambda t: jax.tree_util.tree_map(lambda l: l[jf], t)
-        outs = self._flow_fn(jnp.asarray(xs_new[pad]), gather(z0),
-                             gather(dz0), *sched._flow_args())
+        outs = self._flow_fn(sched.params, jnp.asarray(xs_new[pad]),
+                             gather(z0), gather(dz0), *sched._flow_args())
         rs = [reqs[i] for i in fidx]
         self._staged_flow.append(_FlowBatch(
             n=len(fidx), outs=outs, t_done=t_done,
@@ -539,7 +537,7 @@ class _SlotPool:
         k_old = self.k.copy()
         occ = self.occupied.copy()
         z, fs, meta = segment_fn(
-            self._xs_dev, self.z, jnp.asarray(self.k),
+            self.sched.params, self._xs_dev, self.z, jnp.asarray(self.k),
             jnp.asarray(self.Ks), jnp.asarray(self.eps), self.fs,
             *self.sched._g_args())
         self.z, self.fs = z, fs
@@ -684,7 +682,7 @@ class _SlotPool:
         self._readout_widths.add(int(w))
         jidx = jnp.asarray(pad)
         z_rows = jax.tree_util.tree_map(lambda l: l[jidx], self.z)
-        return readout_fn(self._xs_dev[jidx], z_rows)
+        return readout_fn(self.sched.params, self._xs_dev[jidx], z_rows)
 
     def finalize_retired(self) -> List[InflightCompleted]:
         """Materialize staged completions — the only place readout rows
@@ -792,8 +790,9 @@ class InflightScheduler:
     gathered per-slot ``k``/``Ks`` rows exactly as on one device — slot
     state is data, so the host never needs to know which device holds
     which slot — and the probe path is unchanged (one pool-width probe
-    cell on the default device). ``slots`` must be a multiple of the
-    axis size; checked here with a remedy-naming error.
+    cell). The model weights are placed once, replicated on every device
+    of the mesh, so no segment re-sends them. ``slots`` must be a
+    multiple of the axis size; checked here with a remedy-naming error.
 
     ``overlap=True`` swaps the synchronous tick for the pipelined one
     (serve.py ``--overlap``): segment N+1 is dispatched while segment
@@ -842,6 +841,13 @@ class InflightScheduler:
         self.mesh = mesh
         self.slot_axis = slot_axis
         self.model = model
+        # model weights: one resident copy (replicated over the mesh when
+        # there is one), a traced operand of every pool cell
+        self.params = jax.tree_util.tree_map(jnp.asarray, model.params)
+        if mesh is not None:
+            self.params = jax.device_put(
+                self.params, jax.sharding.NamedSharding(
+                    mesh, jax.sharding.PartitionSpec()))
         self.ecfg = engine_cfg
         self.slots = int(slots)
         self.seg = int(seg)
@@ -865,12 +871,11 @@ class InflightScheduler:
         self.overlap = bool(overlap)
         # Donating the carry buffers halves pool memory on accelerators,
         # where XLA aliases them in place without giving up async
-        # dispatch. The CPU client (jaxlib 0.4.x) runs donated
-        # computations SYNCHRONOUSLY — dispatch blocks until the segment
-        # finishes, which would serialize the overlap pipeline at launch
-        # — so the auto default keeps donation off on CPU; pass
-        # donate=True to force it (the aliasing contract itself compiles
-        # and verifies on every backend — tests/test_scheduler.py).
+        # dispatch. The auto default keeps donation off on the CPU
+        # backend, where the pool lives in host memory and aliasing
+        # saves nothing that bounds a run; pass donate=True to force it
+        # (the aliasing contract itself compiles and verifies on every
+        # backend — tests/test_scheduler.py).
         if donate is None:
             donate = jax.default_backend() != "cpu"
         self.donate = bool(donate)

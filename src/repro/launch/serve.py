@@ -69,6 +69,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.engine import (
     EngineConfig, MultiRateEngine, greedy_generate, lm_depth_model,
     load_flow_params, load_g_params,
@@ -273,10 +274,12 @@ def main():
         raise SystemExit("--flow-ckpt is only read by the flow tier; "
                          "pass --flow-threshold > 0 with it")
 
+    use_compile_cache()
     cfg = get(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    params = init_lm(jax.random.PRNGKey(args.seed), cfg)
+    params = jax.jit(init_lm, static_argnums=1)(
+        jax.random.PRNGKey(args.seed), cfg)
     prompt = jax.random.randint(jax.random.PRNGKey(1),
                                 (args.batch, args.prompt_len), 0, cfg.vocab)
 

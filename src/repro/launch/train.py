@@ -28,7 +28,7 @@ from repro.data import ShardedLoader, token_batches
 from repro.distributed.fault import (
     FailureInjector, StepFailure, StepWatchdog, WatchdogConfig,
 )
-from repro.launch.mesh import mesh_context
+from repro.launch.mesh import auto_mesh
 from repro.launch.steps import StepSettings, make_train_step
 from repro.models.lm import init_lm
 from repro.models import encdec as whisper
@@ -66,7 +66,7 @@ def train_loop(
             log.info("resumed from step %d", latest)
     if params is None:
         init = (whisper.init_encdec if cfg.is_encdec else init_lm)
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             params = jax.jit(
                 lambda k: init(k, cfg), out_shardings=p_sh
             )(jax.random.PRNGKey(seed))
@@ -134,7 +134,7 @@ def main():
     cfg = get(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    mesh = jax.make_mesh((1, 1), ("data", "model")) \
+    mesh = auto_mesh((1, 1), ("data", "model")) \
         if jax.device_count() == 1 else None
     assert mesh is not None, "production launch requires a real device mesh"
     settings = StepSettings(microbatches=args.microbatches, remat="none",

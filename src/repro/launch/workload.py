@@ -383,10 +383,10 @@ def toy_classifier(solver: str = "euler", fused: bool = True, *,
     from repro.core import Integrator, get_tableau
     from repro.launch.engine import DepthModel
 
-    W = np.asarray(jax.random.normal(jax.random.PRNGKey(7),
-                                     (d, n_classes)) / np.sqrt(d))
+    W = jax.random.normal(jax.random.PRNGKey(7),
+                          (d, n_classes)) / np.sqrt(d)
 
-    def field_of(x):
+    def field_of(params, x):
         k = jax.nn.softplus(jnp.mean(x, axis=-1, keepdims=True))
         return lambda s, z: -z * k
 
@@ -397,10 +397,11 @@ def toy_classifier(solver: str = "euler", fused: bool = True, *,
         g = lambda eps, s, z, dz: 0.3 * z + 0.1 * dz
     base = solver[len("hyper_"):] if solver.startswith("hyper_") else solver
     return DepthModel(
-        embed=lambda x: x + 0.0,
+        embed=lambda p, x: x + 0.0,
         field_of=field_of,
-        readout=lambda x, zT: zT @ jnp.asarray(W),
+        readout=lambda p, x, zT: zT @ p["W"],
         integ=Integrator(tableau=get_tableau(base), g=g, fused=fused),
+        params={"W": W},
     )
 
 
@@ -429,13 +430,13 @@ def toy_refinable_classifier(base: str = "euler", fused: bool = True, *,
     from repro.core import Integrator, get_tableau
     from repro.launch.engine import DepthModel
 
-    W = np.asarray(jax.random.normal(jax.random.PRNGKey(7),
-                                     (d, n_classes)) / np.sqrt(d))
+    W = jax.random.normal(jax.random.PRNGKey(7),
+                          (d, n_classes)) / np.sqrt(d)
     w_feat = jnp.asarray(np.linspace(0.4, 1.6, d), jnp.float32)
 
-    def field_of(x):
+    def field_of(params, x):
         k = jax.nn.softplus(jnp.mean(x, axis=-1, keepdims=True))
-        return lambda s, z: -z * (k * w_feat)
+        return lambda s, z: -z * (k * params["w_feat"])
 
     k1, = jax.random.split(jax.random.PRNGKey(seed), 1)
     g_params = {
@@ -458,10 +459,11 @@ def toy_refinable_classifier(base: str = "euler", fused: bool = True, *,
         return (h @ gp["w2"])[..., 0] + gp["b2"][0]
 
     return DepthModel(
-        embed=lambda x: x + 0.0,
+        embed=lambda p, x: x + 0.0,
         field_of=field_of,
-        readout=lambda x, zT: zT @ jnp.asarray(W),
+        readout=lambda p, x, zT: zT @ p["W"],
         integ=Integrator(tableau=get_tableau(base), fused=fused),
+        params={"W": W, "w_feat": w_feat},
         g_apply=g_apply,
         g_params=g_params,
     )

@@ -16,6 +16,7 @@ result (2-NFE sampling) to LM inference.
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Any, Optional
 
 import jax
@@ -38,8 +39,11 @@ def _group_apply(params, cfg: ArchConfig, gp, h):
     return h
 
 
-def depth_field(params, cfg: ArchConfig):
-    """VectorField f(s, h) over the residual stream (full sequence).
+def depth_field(cfg: ArchConfig):
+    """Vector field ``f(params, s, h)`` over the residual stream (full
+    sequence). The weights are an argument, not a closure, so a jit cell
+    that evaluates the field takes them as an operand instead of baking
+    them into its program as constants.
 
     ``s`` may be a scalar or a per-sample ``(B,)`` row (multi-rate solves,
     core/integrate.py ``solve_multirate``): group selection is by depth, so
@@ -48,9 +52,9 @@ def depth_field(params, cfg: ArchConfig):
     stack sees its native (B, S, d) rank)."""
     _, n_groups, _ = group_layout(cfg)
 
-    def f(s, h):
+    def f(params, s, h):
         if jnp.ndim(s):
-            return jax.vmap(lambda si, hi: f(si, hi[None])[0])(
+            return jax.vmap(lambda si, hi: f(params, si, hi[None])[0])(
                 jnp.reshape(s, (-1,)), h)
         idx = jnp.clip(jnp.floor(s * n_groups).astype(jnp.int32), 0,
                        n_groups - 1)
@@ -208,7 +212,7 @@ def lm_forward_cdepth(params, cfg: ArchConfig, tokens: jnp.ndarray, K: int,
         from repro.nn.module import dense
         fe = dense(params["patch_proj"], frontend.astype(h.dtype))
         h = jnp.concatenate([fe, h], axis=1)
-    f = depth_field(params, cfg)
+    f = partial(depth_field(cfg), params)
     integ = lm_integrator(solver, g_params)
     grid = FixedGrid.over(0.0, 1.0, K)
     h = integ.solve(f, h, grid, return_traj=False)
@@ -239,7 +243,7 @@ def depth_probe(params, cfg: ArchConfig, tokens: jnp.ndarray, controller,
         from repro.nn.module import dense
         fe = dense(params["patch_proj"], frontend.astype(h.dtype))
         h = jnp.concatenate([fe, h], axis=1)
-    f = depth_field(params, cfg)
+    f = partial(depth_field(cfg), params)
     integ = lm_integrator(solver, g_params)
     return controller.select(integ, f, h, (0.0, 1.0))
 
@@ -257,7 +261,7 @@ def cdepth_residual_loss(params, g_params, cfg: ArchConfig,
     stride = n_groups // K
     traj_full = discrete_depth_trajectory(params, cfg, tokens)
     traj = traj_full[::stride]  # (K+1, B, S, d)
-    f = depth_field(params, cfg)
+    f = partial(depth_field(cfg), params)
     g = lambda eps, s, z, dz: lm_g_apply(g_params, eps, s, None, z, dz)
     integ = Integrator(tableau=get_tableau(base_solver), g=g)
     grid = FixedGrid.over(0.0, 1.0, K)

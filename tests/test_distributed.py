@@ -22,7 +22,6 @@ _SCRIPT = textwrap.dedent("""
 
     from repro.configs import get
     from repro.distributed import sharding as shd
-    from repro.launch.mesh import mesh_context
     from repro.launch.steps import StepSettings, make_train_step, make_serve_step
     from repro.models.lm import init_lm, init_lm_cache, lm_decode_step
     from repro.data import token_batches
@@ -69,7 +68,7 @@ _SCRIPT = textwrap.dedent("""
     params_host = jax.tree_util.tree_map(np.asarray, params)
 
     # ---- sharded step
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         shd.set_activation_sharding(("data",))
         step, _, (a_p, a_o, p_sh, o_sh) = make_train_step(cfg, settings, mesh)
         params_sh = jax.tree_util.tree_map(
@@ -110,7 +109,7 @@ _SCRIPT = textwrap.dedent("""
     print("COMPRESSED_ALLREDUCE_OK")
 
     # ---- sharded decode parity
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         serve, (a_p2, p_sh2) = make_serve_step(cfg, mesh)
         caches = init_lm_cache(cfg, 8, 16)
         tok = toks[:, 0]

@@ -131,6 +131,7 @@ def test_bench_wildcard_targets_exist():
 _FLAG_ALLOW = {
     "--check", "--budget", "--only",           # benchmarks/run.py
     "--out",                                   # bench_* scripts
+    "--chips",                                 # chip_smoke.py
     "--xla_force_host_platform_device_count",  # XLA_FLAGS env
 }
 

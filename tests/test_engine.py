@@ -66,14 +66,14 @@ def _toy_model(g_scale=None, solver="euler"):
         g = lambda eps, s, z, dz: g_scale * z
     stiff = lambda x: jax.nn.softplus(jnp.mean(x, axis=-1, keepdims=True))
 
-    def field_of(x):
+    def field_of(p, x):
         k = stiff(x)
         return lambda s, z: -z * k
 
     return DepthModel(
-        embed=lambda x: x + 0.0,
+        embed=lambda p, x: x + 0.0,
         field_of=field_of,
-        readout=lambda x, zT: zT,
+        readout=lambda p, x, zT: zT,
         integ=Integrator(tableau=tab, g=g),
     )
 
@@ -126,7 +126,8 @@ def test_engine_outputs_match_direct_solve():
     res = eng.run(xs)
     for i, r in enumerate(res):
         x = jnp.asarray(xs[i:i + 1])
-        direct = model.integ.solve(model.field_of(x), model.embed(x),
+        direct = model.integ.solve(model.field_of(None, x),
+                                   model.embed(None, x),
                                    FixedGrid.over(0.0, 1.0, r.K),
                                    return_traj=False)
         np.testing.assert_allclose(np.asarray(r.outputs),
@@ -213,3 +214,34 @@ def test_lm_engine_end_to_end():
     raw_k, raw_err = eng2.probe(toks)
     np.testing.assert_array_equal(np.asarray(probe.K), raw_k)
     np.testing.assert_allclose(np.asarray(probe.err), raw_err, rtol=1e-6)
+
+
+@pytest.mark.parametrize("loop", ["engine", "inflight"])
+def test_node_depth_model_serves_with_weights_as_operands(loop):
+    """``node_depth_model`` follows the weights-as-operands contract: the
+    NeuralODE's params ride the cells, and both serving loops reproduce
+    the NeuralODE's own fixed-K forward."""
+    from repro.core.neural_ode import NeuralODE
+    from repro.launch.engine import node_depth_model
+    from repro.launch.scheduler import InflightScheduler
+
+    node = NeuralODE(
+        f_apply=lambda p, s, x, z: -z * jax.nn.softplus(p["k"]),
+        hx_apply=lambda p, x: x @ p["win"],
+        hy_apply=lambda p, z: z @ p["wout"])
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(0), 3)
+    params = {"k": jax.random.normal(k1, (8,)),
+              "win": jax.random.normal(k2, (5, 8)),
+              "wout": jax.random.normal(k3, (8, 3))}
+    model = node_depth_model(node, params, solver="euler")
+    assert model.params is params
+    ecfg = EngineConfig(buckets=(4,), controller="fixed", fixed_K=4)
+    xs = np.asarray(jax.random.normal(jax.random.PRNGKey(1), (6, 5)))
+    serving = MultiRateEngine(model, ecfg) if loop == "engine" else \
+        InflightScheduler(model, ecfg, slots=4, seg=2)
+    res = serving.run(xs)
+    ref = node.forward(params, jnp.asarray(xs), "euler", 4)
+    for i, r in enumerate(res):
+        np.testing.assert_allclose(np.asarray(r.outputs),
+                                   np.asarray(ref[i]), rtol=1e-5,
+                                   atol=1e-5)

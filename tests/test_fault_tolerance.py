@@ -1,7 +1,6 @@
 """End-to-end fault tolerance: a training run with injected failures must
 resume from checkpoints and converge to the same trajectory as an
 uninterrupted run (bitwise-identical data stream via skip-ahead)."""
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -10,12 +9,13 @@ from repro.checkpoint import CheckpointManager
 from repro.configs import get
 from repro.data import token_batches
 from repro.distributed.fault import FailureInjector, StepWatchdog, WatchdogConfig
+from repro.launch.mesh import make_debug_mesh
 from repro.launch.steps import StepSettings
 from repro.launch.train import train_loop
 
 
 def _mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_debug_mesh(n_data=1, n_model=1)
 
 
 def _batches(cfg, n=None):

@@ -79,8 +79,9 @@ def test_sharded_segment_rejects_indivisible_slot_count():
     carry = make_segment_carry(jnp.ones((5, 4)), jnp.full((5,), 2),
                                (0.0, 1.0))
     with pytest.raises(ValueError, match="does not divide"):
-        sharded_segment(integ, lambda x: _field, jnp.ones((5, 4)), carry,
-                        2, mesh=_StubMesh(n_data=2))
+        sharded_segment(integ, lambda p, x: _field, None,
+                        jnp.ones((5, 4)), carry, 2,
+                        mesh=_StubMesh(n_data=2))
 
 
 def test_inflight_scheduler_rejects_indivisible_slots():
@@ -89,8 +90,8 @@ def test_inflight_scheduler_rejects_indivisible_slots():
     from repro.launch.engine import DepthModel, EngineConfig
     from repro.launch.scheduler import InflightScheduler
 
-    model = DepthModel(embed=lambda x: x, field_of=lambda x: _field,
-                       readout=lambda x, zT: zT,
+    model = DepthModel(embed=lambda p, x: x, field_of=lambda p, x: _field,
+                       readout=lambda p, x, zT: zT,
                        integ=Integrator(get_tableau("euler")))
     with pytest.raises(ValueError, match="does not divide"):
         InflightScheduler(model, EngineConfig(), slots=5, seg=2,

@@ -146,14 +146,14 @@ def test_solve_multirate_rejects_truncating_k_max():
 def _toy_model(fused=False):
     from repro.launch.engine import DepthModel
 
-    def field_of(x):
+    def field_of(p, x):
         k = jax.nn.softplus(jnp.mean(x, axis=-1, keepdims=True))
         return lambda s, z: -z * k
 
     return DepthModel(
-        embed=lambda x: x + 0.0,
+        embed=lambda p, x: x + 0.0,
         field_of=field_of,
-        readout=lambda x, zT: zT,
+        readout=lambda p, x, zT: zT,
         integ=Integrator(tableau=get_tableau("euler"), fused=fused),
     )
 
@@ -182,7 +182,8 @@ def test_engine_packs_mixed_K_into_one_fused_cell():
     model = _toy_model()
     for i, r in enumerate(res):
         x = jnp.asarray(xs[i:i + 1])
-        direct = model.integ.solve(model.field_of(x), model.embed(x),
+        direct = model.integ.solve(model.field_of(None, x),
+                                   model.embed(None, x),
                                    FixedGrid.over(0.0, 1.0, r.K),
                                    return_traj=False)
         np.testing.assert_allclose(np.asarray(r.outputs),

@@ -49,14 +49,14 @@ G = lambda eps, s, z, dz: 0.25 * z + 0.1 * dz
 
 
 def _toy_model(fused=False, g=None):
-    def field_of(x):
+    def field_of(p, x):
         k = jax.nn.softplus(jnp.mean(x, axis=-1, keepdims=True))
         return lambda s, z: -z * k
 
     return DepthModel(
-        embed=lambda x: x + 0.0,
+        embed=lambda p, x: x + 0.0,
         field_of=field_of,
-        readout=lambda x, zT: zT,
+        readout=lambda p, x, zT: zT,
         integ=Integrator(tableau=get_tableau("euler"), g=g, fused=fused),
     )
 
@@ -368,14 +368,14 @@ _SHARDED_SCRIPT = textwrap.dedent("""
     # sharded pool replay == single-device pool replay, request for
     # request, and ONE fused-kernel trace for the (shape, seg, mesh)
     # cell across every refill pattern the trace produces
-    def field_of(x):
+    def field_of(p, x):
         k = jax.nn.softplus(jnp.mean(x, axis=-1, keepdims=True))
         return lambda s, z: -z * k
 
     def model():
         return DepthModel(
-            embed=lambda x: x + 0.0, field_of=field_of,
-            readout=lambda x, zT: zT,
+            embed=lambda p, x: x + 0.0, field_of=field_of,
+            readout=lambda p, x, zT: zT,
             integ=Integrator(get_tableau("euler"), fused=True))
 
     ecfg = EngineConfig(buckets=(2, 4, 8, 16), tol=5e-3, fused=True)
@@ -798,12 +798,12 @@ def test_segment_cell_donates_carry_buffers():
     k = jnp.zeros((B,), jnp.int32)
     Ks = jnp.full((B,), 4, jnp.int32)
     eps = jnp.full((B,), 0.25, jnp.float32)
-    compiled = cell.lower(xs, z, k, Ks, eps, fs).compile()
+    compiled = cell.lower(m.params, xs, z, k, Ks, eps, fs).compile()
     assert "input_output_alias" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= z.nbytes + fs.nbytes, (
         mem.alias_size_in_bytes)
-    z2, fs2, meta = cell(xs, z, k, Ks, eps, fs)
+    z2, fs2, meta = cell(m.params, xs, z, k, Ks, eps, fs)
     assert z.is_deleted() and fs.is_deleted()
     assert not xs.is_deleted()
     meta = np.array(meta)
@@ -819,7 +819,7 @@ def test_retire_readout_gated_to_finished_rows():
     sub-pool widths and the pool records exactly those widths."""
     traced = []
 
-    def readout(x, zT):
+    def readout(p, x, zT):
         traced.append(zT.shape[0])    # runs at TRACE time only
         return zT
 
