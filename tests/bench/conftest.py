@@ -1,0 +1,9 @@
+"""Tests of the benchmark harness under ``bench/``: they import its
+modules from that directory, as ``bench/run.py`` does."""
+import os
+import sys
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))), "bench")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
