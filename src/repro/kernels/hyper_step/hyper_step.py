@@ -91,4 +91,5 @@ def rk_update_batched(z: jnp.ndarray, stages: Sequence[jnp.ndarray],
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(z.shape, z.dtype),
         interpret=interpret,
+        name="fused_rk_update",
     )(eps_row, epsp_row, active_row, *operands)

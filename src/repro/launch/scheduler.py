@@ -85,12 +85,14 @@ drain loop (``seg >= max bucket`` is exactly a drain with extra steps).
 from __future__ import annotations
 
 import dataclasses
+import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.core.controllers import FixedController, TierRouter
 from repro.core.integrate import SegmentCarry
@@ -235,6 +237,14 @@ class _RetireStats:
     requeued: int = 0
 
 
+def _fetch(outs, rows: int) -> np.ndarray:
+    """Copy a retiring group's readout (``rows`` real rows of a padded
+    device array) to the host."""
+    with TraceAnnotation("inflight.fetch", rows=rows, width=outs.shape[0],
+                         bytes=outs.nbytes):
+        return np.asarray(outs)
+
+
 class _SlotPool:
     """Fixed-width slot pool for one request shape: device-side carry
     (z / first_stage pytrees) + host-side bookkeeping rows (k, Ks, eps,
@@ -293,7 +303,12 @@ class _SlotPool:
                 p = ctrl.select(ig, m.field_of(params, xs), z0, m.span)
                 return p.K, p.err, z0, p.dz0
 
-            embed = jax.jit(m.embed)
+            # embed and readout are named functions, not the adapter's
+            # lambdas, so their programs appear as ``jit_embed`` and
+            # ``jit_readout`` in a profile
+            @jax.jit
+            def embed(params, xs):
+                return m.embed(params, xs)
 
             # the segment cell donates the pool-sized carry buffers
             # (z, fs) — Integrator.segment_cell documents the aliasing
@@ -321,7 +336,9 @@ class _SlotPool:
                     slot_axis=self.sched.slot_axis, donate=donate,
                     g_apply=g_apply)
 
-            readout = jax.jit(m.readout)
+            @jax.jit
+            def readout(params, xs, zT):
+                return m.readout(params, xs, zT)
 
             if m.flow_apply is not None:
                 h, fs0 = m.span[1] - m.span[0], m.span[0]
@@ -361,7 +378,18 @@ class _SlotPool:
         """Probe ``reqs`` (padded to pool width: one probe jit cell per
         shape) and scatter them into free slots. Returns (probe cost,
         non-finite probe count). ``degrade`` caps every admission one
-        bucket coarser (the overload policy's pressure response)."""
+        bucket coarser (the overload policy's pressure response).
+
+        The ``inflight.admit`` span carries the admitted ``rows`` and
+        ``wait_ms``, their summed wall-clock time in the queue."""
+        t = time.perf_counter()
+        wait_s = sum(t - self.sched._queued_at.pop(r.uid) for r in reqs)
+        with TraceAnnotation("inflight.admit", rows=len(reqs),
+                             wait_ms=1e3 * wait_s):
+            return self._admit(reqs, submit_t, now, degrade)
+
+    def _admit(self, reqs: List[Request], submit_t: Dict[int, float],
+               now: float, degrade: bool) -> Tuple[float, int]:
         probe_fn, embed_fn, _, _ = self._cells()
         sched = self.sched
         idx = self.free[:len(reqs)]
@@ -536,10 +564,11 @@ class _SlotPool:
         assert self._xs_dev is not None  # a busy pool has admitted
         k_old = self.k.copy()
         occ = self.occupied.copy()
-        z, fs, meta = segment_fn(
-            self.sched.params, self._xs_dev, self.z, jnp.asarray(self.k),
-            jnp.asarray(self.Ks), jnp.asarray(self.eps), self.fs,
-            *self.sched._g_args())
+        with TraceAnnotation("inflight.launch"):
+            z, fs, meta = segment_fn(
+                self.sched.params, self._xs_dev, self.z,
+                jnp.asarray(self.k), jnp.asarray(self.Ks),
+                jnp.asarray(self.eps), self.fs, *self.sched._g_args())
         self.z, self.fs = z, fs
         self._pending = _PendingSegment(meta=meta, k_old=k_old, occ=occ,
                                         t_done=t_done)
@@ -562,7 +591,9 @@ class _SlotPool:
         assert p is not None, "retire_pending without a pending segment"
         self._pending = None
         sched = self.sched
-        meta = np.array(p.meta)   # the one blocking transfer per segment
+        # the one blocking transfer per segment
+        with TraceAnnotation("inflight.meta_wait"):
+            meta = np.array(p.meta)
         self.k = meta[0]
         occ = p.occ
         self.segments[occ] += 1
@@ -632,6 +663,7 @@ class _SlotPool:
         sched._nfe_extra[uid] = sched._nfe_extra.get(uid, 0) \
             + sched.probe_nfe + sched.stages * int(self.k[i])
         sched._submit_t[uid] = float(self.t_submit[i])
+        sched._queued_at[uid] = time.perf_counter()
         deadline = float(self.deadline[i])
         sched._queue.appendleft(Request(
             uid=uid, x=self.xs[i].copy(),
@@ -680,20 +712,22 @@ class _SlotPool:
         pad = idx if w == len(idx) else np.concatenate(
             [idx, np.repeat(idx[:1], w - len(idx))])
         self._readout_widths.add(int(w))
-        jidx = jnp.asarray(pad)
-        z_rows = jax.tree_util.tree_map(lambda l: l[jidx], self.z)
-        return readout_fn(self.sched.params, self._xs_dev[jidx], z_rows)
+        with TraceAnnotation("inflight.readout", rows=len(idx), width=w):
+            jidx = jnp.asarray(pad)
+            z_rows = jax.tree_util.tree_map(lambda l: l[jidx], self.z)
+            return readout_fn(self.sched.params, self._xs_dev[jidx], z_rows)
 
     def finalize_retired(self) -> List[InflightCompleted]:
         """Materialize staged completions — the only place readout rows
         cross to the host. The overlap loop calls this AFTER dispatching
         the next segments, so the transfer rides behind device work; the
-        sync loop calls it immediately."""
+        sync loop calls it immediately. Each copy is an ``inflight.fetch``
+        span: real ``rows``, padded ``width``, and the ``bytes`` moved."""
         sched = self.sched
         done: List[InflightCompleted] = []
         self.flow_retired_last = 0
         for fb in self._staged_flow:
-            outs = np.asarray(fb.outs)
+            outs = _fetch(fb.outs, fb.n)
             for j in range(fb.n):
                 uid = int(fb.uid[j])
                 attempts = int(fb.attempts[j])
@@ -727,6 +761,7 @@ class _SlotPool:
                     sched._nfe_extra[uid] = \
                         sched._nfe_extra.get(uid, 0) + sched.nfe_flow
                     sched._submit_t[uid] = float(fb.t_submit[j])
+                    sched._queued_at[uid] = time.perf_counter()
                     dl = float(fb.deadline[j])
                     sched._queue.appendleft(Request(
                         uid=uid, x=fb.xs[j].copy(),
@@ -746,7 +781,7 @@ class _SlotPool:
                     t_done=fb.t_done, segments=0, status="diverged"))
         self._staged_flow = []
         for b in self._staged:
-            outs = np.asarray(b.outs)
+            outs = _fetch(b.outs, len(b.idx))
             for j in range(len(b.idx)):
                 uid = int(b.uid[j])
                 # nfe bills the depth steps actually TAKEN (k_done == K
@@ -910,6 +945,9 @@ class InflightScheduler:
         self.fault_injector = fault_injector
         self._queue: deque = deque()
         self._submit_t: Dict[int, float] = {}
+        # wall-clock (perf_counter) time each queued request last entered
+        # the queue; popped at admission for the inflight.admit span
+        self._queued_at: Dict[int, float] = {}
         self._uid = 0
         self._pools: Dict[Tuple, _SlotPool] = {}
         self._shed: List[InflightCompleted] = []   # terminal, pre-admission
@@ -1035,6 +1073,7 @@ class InflightScheduler:
         self._queue.append(Request(uid=self._uid, x=np.asarray(x),
                                    deadline=deadline))
         self._submit_t[self._uid] = t
+        self._queued_at[self._uid] = time.perf_counter()
         return self._uid
 
     def advance_to(self, t: float) -> None:
@@ -1069,8 +1108,11 @@ class InflightScheduler:
         then materialize outputs — so host bookkeeping overlaps device
         compute. Both paths admit identical request->slot assignments
         and stamp identical virtual-clock times; only wall-clock
-        behavior differs."""
-        return self._step_overlap() if self.overlap else self._step_sync()
+        behavior differs. The tick is an ``inflight.tick`` step span
+        (``step_num`` = ``ticks``) in a profiler trace."""
+        with StepTraceAnnotation("inflight.tick", step_num=self.ticks):
+            return self._step_overlap() if self.overlap \
+                else self._step_sync()
 
     def _admit_tick(self) -> Tuple[float, int, Dict[Tuple, float],
                                    List[InflightCompleted], int]:
@@ -1111,6 +1153,7 @@ class InflightScheduler:
                         t_submit=self._submit_t.pop(r.uid),
                         t_admit=self.now, t_done=self.now,
                         segments=0, status="deadline"))
+                    del self._queued_at[r.uid]
                     continue
                 # pools key on (shape, dtype): same-shape requests of a
                 # different dtype must not silently cast into a pool's
