@@ -1,8 +1,9 @@
 """Decide ``correct``: served completions against the plain reference.
 
 After the window, a sample of the completions drawn from the seed is
-scored again by ``reference.py``. Three numbers are compared, each with
-the limit the cell's file states (``check.limits``):
+scored again by ``reference.py`` under the configuration's layout. Three
+numbers are compared, each with the limit the cell's file states
+(``check.limits``):
 
     max_logit_gap   widest gap, over the seeded positions kept of each
                     sampled request, by which the reference's logit of
@@ -51,15 +52,15 @@ def _block_scores(ref, pos, argmax, rows):
     return np.asarray(gap), np.asarray(err)
 
 
-def score(w, gw, m: dict, server: dict, samples: List[dict],
+def score(layout, w, gw, m: dict, server: dict, samples: List[dict],
           block: int = 4, control: bool = False) -> Dict[str, float]:
     """Numbers of the served sample against the reference, and with
     ``control`` also the same numbers for the fp8 reference put in the
     program's place (keys prefixed ``control_``)."""
     tokens = np.stack([s["tokens"] for s in samples])
-    h, K = reference.solve(w, gw, m, server, tokens)
-    hc = reference.solve(w, gw, m, server, tokens, precision="fp8")[0] \
-        if control else None
+    h, K = reference.solve(layout, w, gw, m, server, tokens)
+    hc = reference.solve(layout, w, gw, m, server, tokens,
+                         precision="fp8")[0] if control else None
     # one Euler stage per step, and the residual probe's one evaluation
     # is the first step's stage: NFE = K for both controllers
     out = {"max_logit_gap": 0.0, "logit_rel_err": 0.0,
@@ -70,7 +71,7 @@ def score(w, gw, m: dict, server: dict, samples: List[dict],
     for lo in range(0, len(samples), block):
         part = samples[lo:lo + block]
         pos = np.stack([s["pos"] for s in part])
-        ref = reference.logits(w, m, h[lo:lo + block])
+        ref = reference.logits(layout, w, m, h[lo:lo + block])
         gap, err = _block_scores(ref, pos,
                                  np.stack([s["argmax"] for s in part]),
                                  np.stack([s["rows"] for s in part]))
@@ -78,7 +79,8 @@ def score(w, gw, m: dict, server: dict, samples: List[dict],
         out["logit_rel_err"] = max(out["logit_rel_err"], float(err.max()))
         if control:
             ctl = jnp.take_along_axis(
-                reference.logits(w, m, hc[lo:lo + block], precision="fp8"),
+                reference.logits(layout, w, m, hc[lo:lo + block],
+                                 precision="fp8"),
                 jnp.asarray(pos)[..., None], 1)
             rows = part[0]["rows"].shape[0]
             gap, err = _block_scores(ref, pos, jnp.argmax(ctl, -1),

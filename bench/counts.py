@@ -1,9 +1,10 @@
 """Operations and bytes of the served work, and the chips' peaks.
 
-The FLOP arithmetic is that of ``roofline/costmodel.py`` (attention
-projections and causal core, gated feed-forward, vocabulary readout),
-kept here so that the yardstick does not move with the program. The
-sizes are a configuration file's keys (``spec.model_sizes``).
+The FLOP arithmetic is that of ``roofline/costmodel.py``, kept with the
+benchmark so that the yardstick does not move with the program: the
+vocabulary readout here, a group's and the tail's layers in the
+configuration's layout (``layouts/<layout>.py``). The sizes are a
+configuration file's keys (``spec.model_sizes``).
 """
 from __future__ import annotations
 
@@ -26,32 +27,16 @@ def peaks(device_kind: str) -> Dict[str, float]:
     return table[device_kind]
 
 
-def attention_flops(m: dict, S: int) -> float:
-    """One layer's attention for one causal sequence of S tokens."""
-    H, KV, hd, d = (m["num_attention_heads"], m["num_key_value_heads"],
-                    m["head_dim"], m["hidden_size"])
-    proj = 2 * S * d * (H * hd + 2 * KV * hd) + 2 * S * H * hd * d
-    core = 2 * 2 * H * hd * (S * (S + 1) / 2)
-    return proj + core
-
-
-def ffn_flops(m: dict, S: int) -> float:
-    return 2 * S * m["hidden_size"] * m["intermediate_size"] * 3
-
-
-def field_eval_flops(m: dict, S: int) -> float:
-    """One evaluation of the depth field: one decoder layer."""
-    return attention_flops(m, S) + ffn_flops(m, S)
-
-
 def readout_flops(m: dict, S: int) -> float:
     return 2 * S * m["hidden_size"] * m["vocab_size"]
 
 
-def request_flops(m: dict, S: int, nfe: int) -> float:
-    """Model FLOPs of one scored request: ``nfe`` field evaluations and
-    the readout (the correction g and norms are left out)."""
-    return nfe * field_eval_flops(m, S) + readout_flops(m, S)
+def request_flops(layout, m: dict, S: int, nfe: int) -> float:
+    """Model FLOPs of one scored request: ``nfe`` field evaluations, the
+    tail layers and the readout (the correction g and norms are left
+    out)."""
+    return (nfe * layout.field_eval_flops(m, S) + layout.tail_flops(m, S)
+            + readout_flops(m, S))
 
 
 # ---------------------------------------------------- kernel operands ----

@@ -4,7 +4,8 @@ Each reader is ``metrics/<metric>.py`` with ``read(ctx)``, returning the
 metric's value, or None where the trace holds nothing for it. ``ctx``
 carries the reduced trace (``xplane.Trace``), the scheduler's counters at
 the window's ends, the completions in the traced span, the configuration as run
-(``sizes``), the prompt length, the chip's peaks and the chips in use.
+(``sizes``) and its layout module (``layout``), the prompt length, the
+chip's peaks and the chips in use.
 """
 from __future__ import annotations
 

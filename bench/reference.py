@@ -3,7 +3,7 @@
 A served request is a prompt scored by a decoder read as a depth ODE
 (the paper's continuous-depth model over a pre-norm residual stack):
 
-    f(s, h) = L * (block_{floor(s L)}(h) - h)          L = number of layers
+    f(s, h) = L * (group_{floor(s L)}(h) - h)          L = number of groups
 
 integrated from s = 0 to 1 in K Euler steps of eps = 1/K, with the
 hypersolver correction g (paper Eq. 5) when the solver is ``hyper_euler``:
@@ -14,10 +14,13 @@ hypersolver correction g (paper Eq. 5) when the solver is ``hyper_euler``:
 K comes from the server's controller: ``fixed`` gives ``fixed_K``; the
 residual controller takes one probe of f at s = 0, estimates the error as
 rms(g(1, 0, h0, f(0, h0))), sets K = ceil(err / tol) within the bucket
-range and snaps it up to the next bucket. Then the final norm and the
-vocabulary projection give the logits. The block is the published dense
-decoder layer: RMSNorm, grouped-query attention with RoPE (half split)
-and optional qk-norm, causal softmax, RMSNorm, SwiGLU.
+range and snaps it up to the next bucket. Then the discrete tail layers,
+the final norm and the vocabulary projection give the logits.
+
+What a group and the tail compute, and how many groups there are, is the
+configuration's layout (``layouts/<layout>.py``, ``spec.load_layout``):
+this file holds the depth ODE, g, the controller, the readout and the
+primitives the layouts share.
 
 Everything here is plain ``jax.numpy`` in float32 at the highest matmul
 precision, reads the weights the benchmark made (``weights.py``), and
@@ -28,7 +31,7 @@ change.
 """
 from __future__ import annotations
 
-import math
+import json
 from functools import partial
 
 import jax
@@ -67,39 +70,6 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def block(p, h, m, precision: str):
-    """One decoder layer on h (B, S, d), float32."""
-    B, S, _ = h.shape
-    H, KV, hd = (m["num_attention_heads"], m["num_key_value_heads"],
-                 m["head_dim"])
-    eps = m["rms_norm_eps"]
-    x = _rmsnorm(h, p["ln1"]["scale"], eps)
-    a = p["attn"]
-    q = _linear(x, a["wq"]["kernel"], precision).reshape(B, S, H, hd)
-    k = _linear(x, a["wk"]["kernel"], precision).reshape(B, S, KV, hd)
-    v = _linear(x, a["wv"]["kernel"], precision).reshape(B, S, KV, hd)
-    if m["qk_norm"]:
-        q = _rmsnorm(q, a["q_norm"]["scale"], eps)
-        k = _rmsnorm(k, a["k_norm"]["scale"], eps)
-    q, k = _rope(q, m["rope_theta"]), _rope(k, m["rope_theta"])
-    q = q.reshape(B, S, KV, H // KV, hd)
-    sc = jnp.einsum("bsngh,btnh->bngst", q, k, precision=HI) / math.sqrt(hd)
-    causal = np.tril(np.ones((S, S), bool))
-    sc = jnp.where(causal, sc, -jnp.inf)
-    pr = jax.nn.softmax(sc, axis=-1)
-    ctx = jnp.einsum("bngst,btnh->bsngh", pr, v, precision=HI)
-    h = h + _linear(ctx.reshape(B, S, H * hd), a["wo"]["kernel"], precision)
-    x = _rmsnorm(h, p["ln2"]["scale"], eps)
-    f = p["ffn"]
-    up = jax.nn.silu(_linear(x, f["wg"]["kernel"], precision)) \
-        * _linear(x, f["wi"]["kernel"], precision)
-    return h + _linear(up, f["wd"]["kernel"], precision)
-
-
-def _layer(w, idx):
-    return jax.tree_util.tree_map(lambda l: l[idx], w["groups"]["b0"])
-
-
 def _g(gw, eps, s, h, dz):
     n = (gw["w_s"].shape[0] - 1) // 2
     ang = 2 * jnp.pi * jnp.arange(1, n + 1, dtype=jnp.float32) * s
@@ -110,11 +80,10 @@ def _g(gw, eps, s, h, dz):
     return jnp.matmul(jnp.tanh(pre), gw["w_out"], precision=HI)
 
 
-@partial(jax.jit, static_argnames=("m", "precision"))
-def _field(w, h, idx, *, m, precision):
-    m = dict(m)
-    return m["num_hidden_layers"] * (block(_layer(w, idx), h, m, precision)
-                                     - h)
+@partial(jax.jit, static_argnames=("layout", "m", "precision"))
+def _field(w, h, idx, *, layout, m, precision):
+    m = json.loads(m)
+    return layout.n_fields(m) * (layout.field(w, h, idx, m, precision) - h)
 
 
 @partial(jax.jit, static_argnames=("hyper",))
@@ -131,9 +100,10 @@ def _probe_err(gw, h0, dz0):
     return jnp.sqrt(jnp.mean(corr.reshape(corr.shape[0], -1) ** 2, -1))
 
 
-@partial(jax.jit, static_argnames=("m", "precision"))
-def _readout(w, h, *, m, precision):
-    m = dict(m)
+@partial(jax.jit, static_argnames=("layout", "m", "precision"))
+def _readout(w, h, *, layout, m, precision):
+    m = json.loads(m)
+    h = layout.tail(w, h, m, precision)
     x = _rmsnorm(h, w["ln_f"]["scale"], m["rms_norm_eps"])
     head = w["embed"]["table"].T if m["tie_word_embeddings"] \
         else w["head"]["kernel"]
@@ -150,22 +120,25 @@ def mesh_length(server: dict, err: np.ndarray) -> np.ndarray:
     return buckets[np.minimum(np.searchsorted(buckets, K), len(buckets) - 1)]
 
 
-def _static(m: dict):
-    return tuple(sorted((k, v) for k, v in m.items()
-                        if isinstance(v, (int, float, bool, str))))
+def _static(m: dict) -> str:
+    """``m`` as a static argument of jit, nested groups and lists kept;
+    ``json.loads`` gives it back as it was."""
+    return json.dumps(m, sort_keys=True)
 
 
-def logits(w, m: dict, h, precision: str = "highest"):
-    """Final norm and vocabulary projection of terminal states h."""
-    return _readout(w, h, m=_static(m), precision=precision)
+def logits(layout, w, m: dict, h, precision: str = "highest"):
+    """The layout's tail layers, final norm and vocabulary projection of
+    terminal states h."""
+    return _readout(w, h, layout=layout, m=_static(m), precision=precision)
 
 
-def solve(w, gw, m: dict, server: dict, tokens: np.ndarray,
+def solve(layout, w, gw, m: dict, server: dict, tokens: np.ndarray,
           precision: str = "highest", block: int = 8):
     """Terminal states (B, S, d) float32 and K per request for ``tokens``
     (B, S); ``logits`` turns a block of them into logits.
 
-    ``m`` is the configuration as run (``spec.model_sizes``), ``server``
+    ``layout`` is the configuration's layout module (``spec.load_layout``),
+    ``m`` the configuration as run (``spec.model_sizes``), ``server``
     the cell's server settings. Requests go through in blocks of
     ``block`` rows (the last one padded), so every call has one shape."""
     hyper = server["solver"].startswith("hyper_")
@@ -179,14 +152,14 @@ def solve(w, gw, m: dict, server: dict, tokens: np.ndarray,
         raise SystemExit("bench: controller 'auto' without g probes with an "
                          "embedded pair, which the reference does not model")
     mk = _static(m)
-    L = m["num_hidden_layers"]
+    L = layout.n_fields(m)
     n = len(tokens)
     pad = np.concatenate([tokens, np.repeat(tokens[:1], -n % block, 0)])
     outs, Ks = [], []
     for lo in range(0, len(pad), block):
         h = jnp.take(w["embed"]["table"], jnp.asarray(pad[lo:lo + block]),
                      axis=0).astype(jnp.float32)
-        dz0 = _field(w, h, 0, m=mk, precision=precision)
+        dz0 = _field(w, h, 0, layout=layout, m=mk, precision=precision)
         err = np.zeros(block) if server["controller"] == "fixed" \
             else np.asarray(_probe_err(gw, h, dz0))
         K = mesh_length(server, err)
@@ -195,7 +168,8 @@ def solve(w, gw, m: dict, server: dict, tokens: np.ndarray,
         for hb, dz, Kb in rows:
             for k in range(Kb):
                 if k:
-                    dz = _field(w, hb, k * L // Kb, m=mk, precision=precision)
+                    dz = _field(w, hb, k * L // Kb, layout=layout, m=mk,
+                                precision=precision)
                 hb = _euler(hb, dz, gw, jnp.float32(1.0 / Kb),
                             jnp.float32(k / Kb), hyper=hyper)
             outs.append(hb)

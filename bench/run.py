@@ -104,6 +104,7 @@ def per_layer(names: Dict[str, str], w, tr, sizes, cell, peak,
     comp = [{"nfe": n, "status": s} for s, n in w.traced["done"]]
     ctx = types.SimpleNamespace(
         trace=tr, counters=w.counters, completions=comp, sizes=sizes,
+        layout=cell.layout,
         prompt_len=int(cell.traffic["prompt_len"]), peaks=peak,
         chips=cell.chips, window_s=tr.window_ns * 1e-9)
     out = {}
@@ -131,7 +132,7 @@ def run(name: str, seed: int, seconds: float, trace: bool,
     import xplane
 
     cell = spec.load_cell(name, bench_dir)
-    sizes = spec.model_sizes(cell.config, rehearse)
+    sizes = spec.model_sizes(cell, rehearse)
     devs = jax.devices()
     if devs[0].platform != "tpu" and not rehearse:
         raise SystemExit(f"bench: JAX found no TPU (platform "
@@ -141,7 +142,7 @@ def run(name: str, seed: int, seconds: float, trace: bool,
                          f"{len(devs)}")
     devs = devs[:cell.chips]
     compiles = serve.compile_counter()
-    cfg = spec.arch_config(sizes)
+    cfg = spec.arch_config(sizes, cell.layout)
     log(f"bench: cell={name} seed={seed} seconds={seconds} trace={int(trace)}"
         f" platform={devs[0].platform} kind={devs[0].device_kind!r} "
         f"chips={len(devs)} layers={cfg.n_layers} d_model={cfg.d_model} "
@@ -185,8 +186,8 @@ def run(name: str, seed: int, seconds: float, trace: bool,
 
     failed = sum(w.status.get(u) != "ok" for u in w.in_window)
     t_check = time.perf_counter()
-    numbers = check.score(params, gw, sizes, cell.server, w.samples,
-                          control=control)
+    numbers = check.score(cell.layout, params, gw, sizes, cell.server,
+                          w.samples, control=control)
     log(f"bench: reference check of {len(w.samples)} requests took "
         f"{time.perf_counter() - t_check:.2f} s")
     numbers["not_ok"] = float(failed)

@@ -53,7 +53,7 @@ def build(cell, cfg, seed: int):
     from repro.launch.scheduler import InflightScheduler
     from repro.models.lm import init_lm
 
-    params = weights.model_weights(cfg, seed)
+    params = weights.model_weights(cell.layout, cfg, seed)
     want = jax.eval_shape(lambda k: init_lm(k, cfg), jax.random.PRNGKey(0))
     got = jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), params)
     want = jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), want)

@@ -2,10 +2,11 @@
 
 The benchmark, not the program, makes the weights: they stand for a
 checkpoint that is loaded into the server, and the plain reference in
-``reference.py`` reads the same arrays. The layout is the program's
-parameter tree for a dense decoder (``groups`` stacked on a leading
-depth axis); ``serve.build`` checks it against the program's own
-``init_lm`` shapes before serving.
+``reference.py`` reads the same arrays. Which leaves there are, and their
+shapes, is the configuration's layout (``layouts/<layout>.py``,
+``shapes``): the program's parameter tree, ``groups`` stacked on a
+leading depth axis, ``tail`` layers each on its own. ``serve.build``
+checks it against the program's own ``init_lm`` shapes before serving.
 
 Scales follow the usual fan-in rule; norm scales are drawn around 1 so
 that a norm whose scale is dropped shows in the comparison.
@@ -23,32 +24,6 @@ def key_of(seed: int, salt: int) -> jax.Array:
     k = jax.random.PRNGKey(seed & 0xFFFFFFFF)
     k = jax.random.fold_in(k, (seed >> 32) & 0xFFFFFFFF)
     return jax.random.fold_in(k, salt)
-
-
-def shapes(cfg):
-    """Leaf path -> (shape, kind) for the dense decoder; kind is "norm"
-    (scale around 1) or a fan-in for a normal draw."""
-    G, d, H, KV, hd, F, V = (cfg.n_layers, cfg.d_model, cfg.n_heads,
-                             cfg.n_kv, cfg.d_head, cfg.d_ff, cfg.vocab)
-    out = {
-        ("embed", "table"): ((V, d), 1.0),
-        ("ln_f", "scale"): ((d,), "norm"),
-        ("groups", "b0", "ln1", "scale"): ((G, d), "norm"),
-        ("groups", "b0", "ln2", "scale"): ((G, d), "norm"),
-        ("groups", "b0", "attn", "wq", "kernel"): ((G, d, H * hd), d),
-        ("groups", "b0", "attn", "wk", "kernel"): ((G, d, KV * hd), d),
-        ("groups", "b0", "attn", "wv", "kernel"): ((G, d, KV * hd), d),
-        ("groups", "b0", "attn", "wo", "kernel"): ((G, H * hd, d), H * hd),
-        ("groups", "b0", "ffn", "wi", "kernel"): ((G, d, F), d),
-        ("groups", "b0", "ffn", "wg", "kernel"): ((G, d, F), d),
-        ("groups", "b0", "ffn", "wd", "kernel"): ((G, F, d), F),
-    }
-    if cfg.qk_norm:
-        out[("groups", "b0", "attn", "q_norm", "scale")] = ((G, hd), "norm")
-        out[("groups", "b0", "attn", "k_norm", "scale")] = ((G, hd), "norm")
-    if not cfg.tie_embeddings:
-        out[("head", "kernel")] = ((d, V), d)
-    return out
 
 
 def _draw(key, shape, kind, dtype):
@@ -74,15 +49,17 @@ def _make(key, spec):
     table, dtype = spec
     flat = {path: _draw(jax.random.fold_in(key, i), shape, kind, dtype)
             for i, (path, shape, kind) in enumerate(table)}
-    tree = _nest(flat)
-    tree["tail"] = {}
+    return _nest(flat)
+
+
+def model_weights(layout, cfg, seed: int):
+    """The model's weights in the served dtype, from ``seed``; leaves as
+    ``layout.shapes(cfg)`` lists them, each drawn with its index there."""
+    table = tuple((p, s, k) for p, (s, k) in layout.shapes(cfg).items())
+    tree = _make(key_of(seed, 1), (table, jnp.dtype(cfg.param_dtype)))
+    # the program's tree holds a tail, empty where there are no tail layers
+    tree.setdefault("tail", {})
     return tree
-
-
-def model_weights(cfg, seed: int):
-    """The model's weights in the served dtype, from ``seed``."""
-    table = tuple((p, s, k) for p, (s, k) in shapes(cfg).items())
-    return _make(key_of(seed, 1), (table, jnp.dtype(cfg.param_dtype)))
 
 
 def g_weights(cfg, seed: int, rank: int, out_std: float,
@@ -97,6 +74,4 @@ def g_weights(cfg, seed: int, rank: int, out_std: float,
     table = ((("w_h",), (d, rank), d), (("w_dh",), (d, rank), d),
              (("w_s",), (2 * n_fourier + 1, rank), 11.0),
              (("w_out",), (rank, d), float(out_std) ** -2))
-    out = _make(key_of(seed, 2), (table, jnp.dtype(jnp.float32)))
-    out.pop("tail")
-    return out
+    return _make(key_of(seed, 2), (table, jnp.dtype(jnp.float32)))

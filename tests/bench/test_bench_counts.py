@@ -6,8 +6,10 @@ import os
 import pytest
 
 import counts
+import spec
 
 BENCH = os.path.dirname(os.path.abspath(counts.__file__))
+dense = spec.load_layout("dense_decoder", BENCH)
 
 KERNEL_TEXT = (
     "%fused_rk_update.1 = bf16[8,10240,128]{2,1,0:T(8,128)(2,1)S(1)} "
@@ -35,14 +37,14 @@ def _config(name):
 def test_flops_match_hand_counts(name, proj, ffn, readout):
     m, S = _config(name), 512
     core = 4 * 32 * 128 * (S + 1) / 2
-    assert counts.field_eval_flops(m, S) == S * (proj + core + ffn)
+    assert dense.field_eval_flops(m, S) == S * (proj + core + ffn)
     assert counts.readout_flops(m, S) == S * readout
-    assert counts.request_flops(m, S, 9) == \
+    assert counts.request_flops(dense, m, S, 9) == \
         9 * S * (proj + core + ffn) + S * readout
 
 
 def test_qwen3_4b_field_eval_is_206_mflop_a_token():
-    assert counts.field_eval_flops(_config("qwen3_4b"), 512) / 512 \
+    assert dense.field_eval_flops(_config("qwen3_4b"), 512) / 512 \
         == 206_053_376
 
 

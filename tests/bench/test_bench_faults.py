@@ -14,10 +14,10 @@ def bench(tmp_path_factory):
     return copy_with_tiny_cell(str(tmp_path_factory.mktemp("faults")))
 
 
-def _run(bench, seed=5, control=False):
+def _run(bench, seed=5, control=False, cell="tiny"):
     import run
 
-    return run.run("tiny", seed, 2.0, False, rehearse=True,
+    return run.run(cell, seed, 2.0, False, rehearse=True,
                    bench_dir=bench, control=control)
 
 
@@ -104,10 +104,35 @@ def test_half_of_the_batch_left_out(bench, monkeypatch):
     assert res["correct"] is False
 
 
-def test_control_in_lower_precision_is_not_correct(bench):
-    res = _run(bench, control=True)
+def _control_fails(res):
     assert res["correct"] is True
     limits = {k: v["limit"] for k, v in res["checks"].items()}
     ctl = dict(_numbers(res))
     ctl.update({k[len("control_"):]: v for k, v in res["control"].items()})
     assert not check.verdict(ctl, limits), ctl
+
+
+def test_control_in_lower_precision_is_not_correct(bench):
+    _control_fails(_run(bench, control=True))
+
+
+def test_expert_half_left_out(bench, monkeypatch):
+    # the moe layers' routed half dropped from the program: the check runs
+    # the configuration's own layout, whose reference keeps it
+    from repro.models import lm
+
+    orig = lm.moe_apply_sorted
+
+    def no_experts(params, x, **kw):
+        out = orig(params, x, **kw)
+        return out._replace(y=jnp.zeros_like(out.y))
+
+    monkeypatch.setattr(lm, "moe_apply_sorted", no_experts)
+    res = _run(bench, cell="tiny_moe")
+    assert res["correct"] is False
+    assert _numbers(res)["logit_rel_err"] > res["checks"]["logit_rel_err"][
+        "limit"]
+
+
+def test_control_of_a_layout_that_is_not_dense(bench):
+    _control_fails(_run(bench, control=True, cell="tiny_moe"))

@@ -1,5 +1,6 @@
 """``bench/run.py`` end to end: a CPU rehearsal of a cell added as a file,
-and the refusals without a chip or without the program."""
+and of a model that is not dense added as files, and the refusals
+without a chip or without the program."""
 import json
 import os
 import shutil
@@ -22,7 +23,9 @@ def _run(cwd, bench, *args, timeout=600):
 
 @pytest.mark.parametrize("cell, trace", [("tiny", "0"), ("tiny", "1"),
                                          ("tiny_open", "0"),
-                                         ("tiny_mesh4", "0")])
+                                         ("tiny_mesh4", "0"),
+                                         ("tiny_moe", "0"),
+                                         ("tiny_moe", "1")])
 def test_rehearsal_of_an_added_cell(tmp_path, cell, trace):
     bench = copy_with_tiny_cell(str(tmp_path))
     r = _run(tmp_path, bench, "--workload", cell, "--seed", str(2**31 + 7),
